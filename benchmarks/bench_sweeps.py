@@ -214,9 +214,10 @@ _ARENA_SENTINEL = "ARENA-SWEEP-JSON:"
 
 
 def _arena_measure(s_values, rounds: int, smoke: bool) -> dict:
-    """Runs INSIDE the arena subprocess (forced multi-device CPU): for
-    each S, time the full grid-execution WORKFLOW of a mixed-controller
-    grid three ways — S host-looped iterations (per-rollout channel
+    """Runs over ``jax.devices()`` (forced host devices in the CPU
+    subprocess, the chips in process on an accelerator): for each S,
+    time the full grid-execution WORKFLOW of a mixed-controller grid
+    three ways — S host-looped iterations (per-rollout channel
     generation + ``run_scan``, the pre-arena workflow), the vmapped
     single-device ``Arena.run``, and the scenario-sharded
     ``Arena(mesh=..., batch='map')`` run (whole rollouts per local
@@ -515,7 +516,6 @@ def _mixed_k_measure(trainer, rounds: int, smoke: bool) -> dict:
     mk["auto_rounds_per_sec"] = s_count * rounds / best
     mk["auto_steady_dispatches"] = rep.meta["dispatches"]
     mk["auto_steady_executables"] = warm["executables_built"]
-    mk["auto_warmup_aot"] = warm["aot"]
     mk["auto_steady_plan"] = rep.meta["plan"]
     mk["speedup_auto_vs_grouped_workflow"] = (
         mk["grouped_cold_seconds"] / mk["auto_cold_seconds"])
@@ -654,6 +654,42 @@ def planner_guard() -> List[str]:
         f"skewed_cold_buckets={cold.num_buckets}")]
 
 
+def _arena_subprocess(s_values, rounds: int, smoke: bool) -> dict:
+    """:func:`_arena_measure` in a child process on ``_ARENA_SHARDS``
+    forced host devices (CPU only — the child never touches an
+    accelerator)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # single-threaded eigen: the sharded program already keeps every core
+    # busy with one shard each; per-op multi-threading on top only adds
+    # pool contention (it speeds the host loop too — the flag applies to
+    # both sides of the comparison)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        f" --xla_force_host_platform_device_count="
+                        f"{_ARENA_SHARDS}"
+                        " --xla_cpu_multi_thread_eigen=false")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(root / "src"), str(root)] +
+        ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    spec = json.dumps({"s_values": list(s_values), "rounds": rounds,
+                       "smoke": smoke})
+    out = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_sweeps",
+         "--arena-subprocess", spec],
+        env=env, capture_output=True, text=True, timeout=3600)
+    if out.returncode != 0:
+        raise RuntimeError(f"arena subprocess failed:\n{out.stdout}\n"
+                           f"{out.stderr}")
+    payload = [line for line in out.stdout.splitlines()
+               if line.startswith(_ARENA_SENTINEL)]
+    return json.loads(payload[-1][len(_ARENA_SENTINEL):])
+
+
 def arena_sweep(cfg: BenchConfig, s_values=(4, 16), rounds: int = 5,
                 smoke: bool = False, json_path: Optional[str] = None
                 ) -> List[str]:
@@ -683,9 +719,12 @@ def arena_sweep(cfg: BenchConfig, s_values=(4, 16), rounds: int = 5,
     chunked pipeline — chunked-vs-monolithic bitwise guard + overhead,
     and the ``SweepService``'s sustained scenarios/sec against the
     one-shot batched floor.
-    Measurement runs in a subprocess because the forced host-device
-    count must be set before jax initialises; :func:`planner_guard`
-    asserts the planner's split/no-split contract host-side.
+    On an accelerator the measurement runs in process over
+    ``jax.devices()``: a chip belongs to one process, and this one
+    already holds it.  On the CPU it runs in a subprocess pinned to the
+    CPU, because the forced host-device count must be set before jax
+    initialises; :func:`planner_guard` asserts the planner's
+    split/no-split contract host-side.
 
     Scaling note: the sharded row's ceiling is the local device count.
     On the 2-core recording host the fused per-rollout scan baseline
@@ -695,39 +734,15 @@ def arena_sweep(cfg: BenchConfig, s_values=(4, 16), rounds: int = 5,
     devices than the baseline can itself exploit (any accelerator host,
     or a >= 4-core CPU).
     """
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
+    import jax
 
     if json_path is None:
         json_path = ("BENCH_round_engine.smoke.json" if smoke
                      else "BENCH_round_engine.json")
-    root = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    # single-threaded eigen: the sharded program already keeps every core
-    # busy with one shard each; per-op multi-threading on top only adds
-    # pool contention (it speeds the host loop too — the flag applies to
-    # both sides of the comparison)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{_ARENA_SHARDS}"
-                        " --xla_cpu_multi_thread_eigen=false")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root)] +
-        ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    spec = json.dumps({"s_values": list(s_values), "rounds": rounds,
-                       "smoke": smoke})
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sweeps",
-         "--arena-subprocess", spec],
-        env=env, capture_output=True, text=True, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"arena subprocess failed:\n{out.stdout}\n"
-                           f"{out.stderr}")
-    payload = [line for line in out.stdout.splitlines()
-               if line.startswith(_ARENA_SENTINEL)]
-    stats = json.loads(payload[-1][len(_ARENA_SENTINEL):])
+    if jax.default_backend() == "cpu":
+        stats = _arena_subprocess(s_values, rounds, smoke)
+    else:
+        stats = _arena_measure(s_values, rounds, smoke)
     rows = []
     for s_count in s_values:
         sec = stats[f"S{s_count}"]

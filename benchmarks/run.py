@@ -52,6 +52,9 @@ def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     skip = set(filter(None, args.skip.split(",")))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks.common import BenchConfig
     if args.smoke:
         cfg = smoke_config()

@@ -22,6 +22,7 @@ import numpy as np
 from benchmarks.common import BenchConfig, build_testbed
 from repro.core import estimate_hyperparams
 from repro.fl import ClientConfig, RoundEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import paper_step_decay
 from repro.sim import Arena, EvalBank, ScenarioGrid
 
@@ -80,6 +81,7 @@ def main():
                     help="record a flight-recorder span log (JSONL); "
                          "render with tools/obs_report.py")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sink = None
     if args.obs:

@@ -135,15 +135,18 @@ def _local_sgd_body(loss_fn, params: PyTree, x: jax.Array, y: jax.Array,
             # duplicate multiset the sequential tile-to-one-batch path
             # uses (see module docstring)
             scores = jnp.where(jnp.arange(n) < num_examples, scores, 2.0)
-        perm = jnp.argsort(scores)
-        xs = jnp.take(x, perm[:steps_per_epoch * bs], axis=0)
-        ys = jnp.take(y, perm[:steps_per_epoch * bs], axis=0)
-        xs = xs.reshape((steps_per_epoch, bs) + x.shape[1:])
-        ys = ys.reshape((steps_per_epoch, bs) + y.shape[1:])
+        perm = jnp.argsort(scores)[:steps_per_epoch * bs].reshape(
+            steps_per_epoch, bs)
 
         def step(carry, batch):
             params, opt_state = carry
-            si, bx, by = batch
+            si, idx = batch
+            # gather each batch inside the step: a whole-epoch permuted
+            # copy of the bucket (plus the transpose the scan needs under
+            # vmap) would hold two more [lanes, K, B, ...] buffers on the
+            # device at once
+            bx = jnp.take(x, idx, axis=0)
+            by = jnp.take(y, idx, axis=0)
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, {"x": bx, "y": by})
             if cfg.max_grad_norm > 0:
@@ -161,8 +164,7 @@ def _local_sgd_body(loss_fn, params: PyTree, x: jax.Array, y: jax.Array,
             return (new_params, new_opt), loss
 
         (params, opt_state), losses = jax.lax.scan(
-            step, (params, opt_state),
-            (jnp.arange(steps_per_epoch), xs, ys))
+            step, (params, opt_state), (jnp.arange(steps_per_epoch), perm))
         if num_steps is None:
             epoch_loss = jnp.mean(losses)
         else:

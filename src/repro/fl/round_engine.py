@@ -77,7 +77,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import policy as pol
@@ -265,11 +264,11 @@ class RoundEngine:
                     params, deltas, coeffs, csel, num_clusters, axis)
                 return new_params, losses
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body_h, mesh=self.mesh,
                 in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis),
                           P(axis), P(axis), P(axis)),
-                out_specs=(P(), P(axis)), check_rep=False)
+                out_specs=(P(), P(axis)), check_vma=False)
             return sharded(params, lr, xs, ys, coeffs, rngs, num_steps,
                            num_examples, cluster_sel)
 
@@ -283,11 +282,11 @@ class RoundEngine:
 
         # P(axis) specs on the None masks apply to zero leaves — one spec
         # tuple covers both the masked and unmasked traces.
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis),
                       P(axis), P(axis)),
-            out_specs=(P(), P(axis)), check_rep=False)
+            out_specs=(P(), P(axis)), check_vma=False)
         return sharded(params, lr, xs, ys, coeffs, rngs, num_steps,
                        num_examples)
 

@@ -163,9 +163,6 @@ class FederatedTrainer:
         Sequential path: one ``local_update`` per distinct post-padding
         data shape (``local_update``'s jit specializes on the array
         shape, not just the step count).  All outputs are discarded.
-        Warmup *executes* real calls rather than AOT ``lower().compile()``
-        because the AOT path does not populate the jit call cache — a
-        subsequent real call would trace and compile again.
         """
         rng = jax.random.PRNGKey(0)
         sizes = [int(s) for s in self.bank.sizes]

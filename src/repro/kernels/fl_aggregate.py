@@ -25,9 +25,13 @@ Array = jax.Array
 
 
 def _aggregate_kernel(coeff_ref, theta_ref, delta_ref, out_ref):
-    deltas = delta_ref[...].astype(jnp.float32)          # [K, block]
-    coeffs = coeff_ref[...].astype(jnp.float32)          # [K]
-    upd = jnp.einsum("k,kn->n", coeffs, deltas)
+    # SMEM holds scalars only: read one coefficient at a time (K is static,
+    # so the loop unrolls) instead of loading the [K] ref as a vector,
+    # which the TPU compiler refuses.
+    k = delta_ref.shape[0]
+    upd = coeff_ref[0] * delta_ref[0, :].astype(jnp.float32)
+    for i in range(1, k):
+        upd = upd + coeff_ref[i] * delta_ref[i, :].astype(jnp.float32)
     out_ref[...] = (theta_ref[...].astype(jnp.float32) +
                     upd).astype(out_ref.dtype)
 

@@ -144,7 +144,9 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool,
     else:
         model_flops = rf.decode_model_flops(cfg.active_param_count(), tokens)
 
+    # the production mesh models a v5e pod
     terms = rf.roofline_terms(analysis, cost, chips=chips,
+                              device_kind="TPU v5 lite",
                               model_flops=model_flops)
     result = {
         "arch": arch, "shape": shape_name, "mesh": _mesh_tag(multi_pod),

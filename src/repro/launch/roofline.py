@@ -1,10 +1,11 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, from the peaks of the
+named device kind (``repro.launch.mesh.DEVICE_PEAKS``):
 
-    compute    = HLO_FLOPs   / (chips * 197e12)
-    memory     = HLO_bytes   / (chips * 819e9)
-    collective = collective_bytes / (chips * 50e9)
+    compute    = HLO_FLOPs   / (chips * flops_bf16)
+    memory     = HLO_bytes   / (chips * hbm_bw)
+    collective = collective_bytes / (chips * ici_bw)
 
 ``cost_analysis`` of the SPMD-partitioned executable reports **per-device**
 flops/bytes, so global = per_device * chips and the division by chips
@@ -22,7 +23,7 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -97,21 +98,24 @@ def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
 
 
 def roofline_terms(hlo_analysis: Dict, xla_cost: Dict[str, float],
-                   *, chips: int, model_flops: float = 0.0) -> Dict:
+                   *, chips: int, device_kind: str,
+                   model_flops: float = 0.0) -> Dict:
     """``hlo_analysis``: loop-aware per-device numbers from
     ``repro.launch.hlo_cost.analyze`` (XLA's own cost_analysis counts while
-    bodies once — see that module); ``xla_cost`` kept for cross-checking."""
+    bodies once — see that module); ``xla_cost`` kept for cross-checking.
+    ``device_kind`` selects the peaks (unknown kinds raise)."""
+    peaks = device_peaks(device_kind)
     flops = float(hlo_analysis["flops"])
     bytes_accessed = float(hlo_analysis["bytes"])
     coll_operand = float(hlo_analysis["collective_operand_bytes"])
     coll_traffic = float(hlo_analysis["collective_traffic_bytes"])
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll_operand / ICI_BW
+    compute_s = flops / peaks["flops_bf16"]
+    memory_s = bytes_accessed / peaks["hbm_bw"]
+    collective_s = coll_operand / peaks["ici_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s,
-             "collective_traffic_s": coll_traffic / ICI_BW,
+             "collective_traffic_s": coll_traffic / peaks["ici_bw"],
              "hlo_flops_per_device": flops,
              "hlo_bytes_per_device": bytes_accessed,
              "collective_operand_bytes": coll_operand,

@@ -10,8 +10,8 @@ reduction overlapped with device dispatch) behind a long-lived
 checkpoints), and structured RolloutReports with the paper's Sec. VII
 trade-off reducers."""
 
-from repro.sim.arena import (Arena, ScenarioGrid, aot_cache_warmup_supported,
-                             derive_hyperparams, scenario_keys)
+from repro.sim.arena import (Arena, ScenarioGrid, derive_hyperparams,
+                             scenario_keys)
 from repro.sim.cost_model import CostModel
 from repro.sim.dispatch import (DispatchBucket, DispatchPlan,
                                 lane_footprints, plan_dispatch)

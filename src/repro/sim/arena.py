@@ -91,7 +91,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import policy as pol
@@ -109,34 +108,6 @@ from repro.sim.report import RolloutReport, concat_chunk_metrics
 
 PyTree = Any
 
-_AOT_WARMUP_SUPPORTED: Optional[bool] = None
-
-
-def aot_cache_warmup_supported() -> bool:
-    """Does THIS jax populate the jit call cache from AOT
-    ``jit(f).lower(...).compile()``?  Probed once per process with a
-    trace-counting scalar function: lower+compile it, then call it — if
-    the call re-traces, AOT warming buys nothing and ``Arena.warmup``
-    must fall back to executing a real run.  (jax 0.4.x re-traces; the
-    probe keeps the warmup honest across jax upgrades instead of
-    hard-coding a version check.)"""
-    global _AOT_WARMUP_SUPPORTED
-    if _AOT_WARMUP_SUPPORTED is None:
-        traces: List[int] = []
-
-        def probe(x):
-            traces.append(1)
-            return x + 1.0
-
-        fn = jax.jit(probe)
-        x = jnp.zeros(())
-        try:
-            fn.lower(x).compile()
-            jax.block_until_ready(fn(x))
-            _AOT_WARMUP_SUPPORTED = len(traces) == 1
-        except Exception:       # pragma: no cover - AOT API missing
-            _AOT_WARMUP_SUPPORTED = False
-    return _AOT_WARMUP_SUPPORTED
 
 def _as_f32(value, s: int) -> np.ndarray:
     return np.broadcast_to(np.asarray(value, np.float32), (s,)).copy()
@@ -851,12 +822,12 @@ class Arena:
             ax = self.mesh_axis
             p_spec = P(ax) if resume else P()
             d_spec = P(ax) if use_dropout else P()
-            batched = shard_map(
+            batched = jax.shard_map(
                 batched, mesh=self.mesh,
                 in_specs=(p_spec, P(ax), P(), P(ax), P(), P(ax), d_spec,
                           P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax),
                           P(), P(), p_spec),
-                out_specs=P(ax), check_rep=False)
+                out_specs=P(ax), check_vma=False)
         # the queue carry (argnum 1) is donated off-CPU: the arena
         # allocates it per run, so the padded program reuses that buffer
         # for the [S, N] carry instead of holding both — part of the
@@ -921,8 +892,8 @@ class Arena:
         planner's scan-skip lever; the caller guarantees the group's
         lanes never select outside it.  ``warm_aot=True`` AOT-lowers and
         compiles the executable(s) instead of running (results come back
-        None) — only useful where :func:`aot_cache_warmup_supported`
-        says the jit call cache is populated by it.
+        None); the compile lands in the jit call cache, so the next real
+        call neither traces nor compiles.
 
         The chunked pipeline: chunk 0 runs the START executable (the
         monolithic program at segment length — the initial in-scan eval
@@ -1610,7 +1581,6 @@ class Arena:
                grid: ScenarioGrid, num_rounds: int,
                lr_seq=None, *, h_all: Optional[jax.Array] = None,
                eval_bank=None, eval_every: Optional[int] = None,
-               aot: Optional[bool] = None,
                chunk_size: Optional[int] = None) -> dict:
         """Compile EVERY executable a same-shape :meth:`run` will hit,
         so iterating on grid VALUES (the V/lam/seed/channel sweep
@@ -1622,16 +1592,13 @@ class Arena:
         snap to via the cache-aware cost model), each bucket warmed
         individually.
 
-        ``aot`` picks how: ``True`` forces AOT
-        ``jit(...).lower(...).compile()`` per bucket (no paid real
-        execution), ``False`` forces one real discarded run, ``None``
-        (default) asks :func:`aot_cache_warmup_supported` whether this
-        jax populates the jit call cache from AOT — falling back
-        cleanly to the executed path otherwise (jax 0.4.x re-traces on
-        call, so AOT warming there would compile everything twice).
-        Nothing observable changes — the arena holds no rollout state,
-        the bank is read-only, params are never donated.  Returns
-        ``{'executables_built', 'executables_cached', 'traces', 'aot',
+        Every bucket is compiled ahead of time
+        (``jit(...).lower(...).compile()`` from shapes — nothing runs on
+        the device, and the compile fills the jit call cache), so a
+        compile failure surfaces here, before any real run.  Nothing
+        observable changes — the arena holds no rollout state, the bank
+        is read-only, params are never donated.  Returns
+        ``{'executables_built', 'executables_cached', 'traces',
         'plan'}`` for the zero-retrace assertion; subsequent same-shape
         runs keep ``self.traces`` constant.
 
@@ -1671,26 +1638,17 @@ class Arena:
             plan = DispatchPlan.grouped(grid.sample_count)
         else:
             plan = DispatchPlan.padded(grid.sample_count)
-        use_aot = (bool(aot) if aot is not None
-                   else aot_cache_warmup_supported())
-        params, _, _, built, _ = self._run_plan(
+        _, _, _, built, _ = self._run_plan(
             global_params, sp, bank, grid, h_all, lr_seq, plan,
             eval_bank=eval_bank, eval_every=eval_every,
-            warm_aot=use_aot, chunk_size=chunk_size, drop_all=drop_all)
-        if use_aot:
-            if eval_bank is not None:
-                eval_bank.aot_warm(len(grid), global_params)
-        else:
-            # executed path: block on (and discard) the real results,
-            # and run the final batched evaluation so the EvalBank's
-            # stacked executable is warmed too
-            jax.block_until_ready(jax.tree_util.tree_leaves(params))
-            self._final_eval(eval_bank, params)
+            warm_aot=True, chunk_size=chunk_size, drop_all=drop_all)
+        if eval_bank is not None:
+            eval_bank.aot_warm(len(grid), global_params)
         result = {"executables_built": built,
                   "executables_cached": len(self._fns),
                   "traces": self.traces - before,
-                  "aot": use_aot, "plan": plan.describe()}
-        warm_span.set(executables_built=int(built), aot=bool(use_aot))
+                  "plan": plan.describe()}
+        warm_span.set(executables_built=int(built))
         warm_span.__exit__(None, None, None)
         if self.watchdog is not None:
             self.watchdog.arm(self)

@@ -97,17 +97,11 @@ class EvalBank:
         return {name: jax.ShapeDtypeStruct((s,) + tuple(v.shape), v.dtype)
                 for name, v in out.items()}
 
-    def aot_warm(self, s: int, params_example: PyTree) -> bool:
+    def aot_warm(self, s: int, params_example: PyTree) -> None:
         """AOT-compile the stacked evaluator for an ``[s, ...]`` params
         stack from shape structs alone (no execution) — the EvalBank
-        half of ``Arena.warmup(aot=True)``.  Only populates the jit call
-        cache where ``repro.sim.arena.aot_cache_warmup_supported`` says
-        this jax does; returns whether the lowering itself succeeded."""
+        half of ``Arena.warmup``; the compile fills the jit call cache."""
         structs = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct((s,) + tuple(a.shape),
                                            a.dtype), params_example)
-        try:
-            self._stacked.lower(structs, (self.x, self.y)).compile()
-            return True
-        except Exception:       # pragma: no cover - AOT API missing
-            return False
+        self._stacked.lower(structs, (self.x, self.y)).compile()

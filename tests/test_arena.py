@@ -24,8 +24,7 @@ from repro.data import synthetic_image_classification
 from repro.fl import ClientConfig, RoundEngine
 from repro.models import MLPTask
 from repro.sim import (Arena, CostModel, EvalBank, RolloutReport,
-                       ScenarioGrid, aot_cache_warmup_supported,
-                       derive_hyperparams, scenario_keys)
+                       ScenarioGrid, derive_hyperparams, scenario_keys)
 
 N = 6
 BS = 16
@@ -857,10 +856,10 @@ def test_auto_tiered_bank_static_tier_subsets_match_pad_lanes():
 
 def test_auto_warmup_warms_every_steady_bucket():
     """Arena.warmup under 'auto' plans at the steady-state horizon,
-    warms EVERY bucket of that plan (AOT-lowered where supported, one
-    discarded execution otherwise), and subsequent runs re-pick the
-    cached buckets through the cache-aware cost model: zero new
-    compiles, zero new traces."""
+    AOT-compiles EVERY bucket of that plan without running it, and
+    subsequent runs re-pick the cached buckets through the cache-aware
+    cost model: zero new compiles, zero new traces — and the lanes of a
+    cold, unwarmed arena."""
     task, eng, bank, sp, params0 = _setup()
     grid = _mixed_k_grid()
     T = 3
@@ -868,7 +867,6 @@ def test_auto_warmup_warms_every_steady_bucket():
     auto = Arena(eng, k_mode="auto")
     h_all = auto.sample_channels(grid, T, N)
     stats = auto.warmup(params0, sp, bank, grid, T, h_all=h_all)
-    assert stats["aot"] == aot_cache_warmup_supported()
     assert len(stats["plan"]) == 3        # steady split, not the cold pad
     assert stats["executables_built"] == 3
     assert len(auto._fns) == 3
@@ -880,15 +878,12 @@ def test_auto_warmup_warms_every_steady_bucket():
     acc = rep.dispatch_accounting()
     assert acc["dispatches"] == 3
     assert acc["lanes_covered"] == len(grid)
-    # the executed fallback warms the same set
-    auto2 = Arena(eng, k_mode="auto")
-    stats2 = auto2.warmup(params0, sp, bank, grid, T, h_all=h_all,
-                          aot=False)
-    assert stats2["aot"] is False
-    assert stats2["executables_built"] == 3
-    rep2 = auto2.run(params0, sp, bank, grid, T, lr, h_all=h_all)
-    assert rep2.meta["executables_built"] == 0
-    for name in rep.metrics:
+    # warming changes no result: a cold arena (one padded dispatch)
+    # gives the same model trajectory in every lane
+    cold = Arena(eng, k_mode="auto")
+    rep2 = cold.run(params0, sp, bank, grid, T, lr, h_all=h_all)
+    assert rep2.meta["dispatches"] == 1
+    for name in BITWISE_METRICS:
         np.testing.assert_array_equal(rep.metrics[name],
                                       rep2.metrics[name])
 

@@ -307,7 +307,6 @@ def test_aggregate_fused_leaf_chunked_off_tpu_matches_ravelled():
 
 def test_aggregate_fused_psum_single_shard_matches_unsharded():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.fl import aggregate_fused_psum
     mesh = jax.make_mesh((1,), ("data",))
@@ -316,9 +315,10 @@ def test_aggregate_fused_psum_single_shard_matches_unsharded():
     deltas = {"w": jax.random.normal(jax.random.fold_in(key, 1), (2, 4, 3))}
     coeffs = jnp.asarray([0.7, 0.3], jnp.float32)
     body = partial(aggregate_fused_psum, axis_name="data")
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(P(), P("data"), P("data")),
-                    out_specs=P(), check_rep=False)(params, deltas, coeffs)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(), P("data"), P("data")),
+                        out_specs=P(), check_vma=False)(params, deltas,
+                                                        coeffs)
     expected = aggregate_fused(params, deltas, coeffs)
     _assert_trees_bitwise(out, expected)
 

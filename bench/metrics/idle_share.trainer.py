@@ -1,0 +1,7 @@
+"""Share of the traced window in which the device ran nothing (trainer cells)."""
+
+from bench.readers import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx)

@@ -1,0 +1,8 @@
+"""Useful model FLOPs over the window x chips x bf16 peak (trainer cells):
+3 x forward per true example trained, 1 x forward per evaluated row."""
+
+from bench.readers import mfu
+
+
+def read(ctx):
+    return mfu(ctx)

@@ -69,6 +69,7 @@ import numpy as np
 # numpy-only data layer (shared with ClientBank construction) and are
 # re-exported here because they are part of the client-side contract.
 from repro.data.pipeline import bucket_num_batches, pad_client_data
+from repro.obs import scopes
 from repro.optim import SGD, apply_updates
 
 PyTree = Any
@@ -125,18 +126,21 @@ def _local_sgd_body(loss_fn, params: PyTree, x: jax.Array, y: jax.Array,
         # draw, so a mask covering the full bucket reproduces the
         # unmasked ordering bit-for-bit — the ClientBank path (always
         # masked) stays bit-identical to an unmasked host-stacked round.
-        scores = jax.random.uniform(erng, (n,))
-        if num_examples is not None:
-            # without-replacement sample of the true examples: padded rows
-            # get a sentinel key and sort last (stable, so in index
-            # order), out of reach of the num_steps applied batches when
-            # num_examples >= bs; a tiny client (num_examples < bs) fills
-            # its single batch with the first padded rows — the same
-            # duplicate multiset the sequential tile-to-one-batch path
-            # uses (see module docstring)
-            scores = jnp.where(jnp.arange(n) < num_examples, scores, 2.0)
-        perm = jnp.argsort(scores)[:steps_per_epoch * bs].reshape(
-            steps_per_epoch, bs)
+        with jax.named_scope(scopes.SGD_SHUFFLE):
+            scores = jax.random.uniform(erng, (n,))
+            if num_examples is not None:
+                # without-replacement sample of the true examples: padded
+                # rows get a sentinel key and sort last (stable, so in
+                # index order), out of reach of the num_steps applied
+                # batches when num_examples >= bs; a tiny client
+                # (num_examples < bs) fills its single batch with the
+                # first padded rows — the same duplicate multiset the
+                # sequential tile-to-one-batch path uses (see module
+                # docstring)
+                scores = jnp.where(jnp.arange(n) < num_examples, scores,
+                                   2.0)
+            perm = jnp.argsort(scores)[:steps_per_epoch * bs].reshape(
+                steps_per_epoch, bs)
 
         def step(carry, batch):
             params, opt_state = carry
@@ -145,22 +149,27 @@ def _local_sgd_body(loss_fn, params: PyTree, x: jax.Array, y: jax.Array,
             # copy of the bucket (plus the transpose the scan needs under
             # vmap) would hold two more [lanes, K, B, ...] buffers on the
             # device at once
-            bx = jnp.take(x, idx, axis=0)
-            by = jnp.take(y, idx, axis=0)
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, {"x": bx, "y": by})
-            if cfg.max_grad_norm > 0:
-                from repro.optim import clip_by_global_norm
-                grads = clip_by_global_norm(grads, cfg.max_grad_norm)
-            updates, new_opt = opt.update(grads, opt_state, params, lr)
-            new_params = apply_updates(params, updates)
-            if num_steps is not None:
-                keep = si < num_steps
-                new_params = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(keep, a, b), new_params, params)
-                new_opt = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(keep, a, b), new_opt, opt_state)
-                loss = jnp.where(keep, loss, 0.0)
+            with jax.named_scope(scopes.SGD_BATCH):
+                bx = jnp.take(x, idx, axis=0)
+                by = jnp.take(y, idx, axis=0)
+            with jax.named_scope(scopes.SGD_GRAD):
+                loss, grads = jax.value_and_grad(loss_fn)(
+                    params, {"x": bx, "y": by})
+                if cfg.max_grad_norm > 0:
+                    from repro.optim import clip_by_global_norm
+                    grads = clip_by_global_norm(grads, cfg.max_grad_norm)
+            with jax.named_scope(scopes.SGD_UPDATE):
+                updates, new_opt = opt.update(grads, opt_state, params, lr)
+                new_params = apply_updates(params, updates)
+                if num_steps is not None:
+                    keep = si < num_steps
+                    new_params = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(keep, a, b), new_params,
+                        params)
+                    new_opt = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(keep, a, b), new_opt,
+                        opt_state)
+                    loss = jnp.where(keep, loss, 0.0)
             return (new_params, new_opt), loss
 
         (params, opt_state), losses = jax.lax.scan(

@@ -81,6 +81,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import policy as pol
 from repro.core import queues as vq
+from repro.obs import scopes
 from repro.obs import trace as obs_trace
 from repro.core import system_model as sm
 from repro.fl import client as fl_client
@@ -239,15 +240,17 @@ class RoundEngine:
         loss_fn, cfg, impl = self.task.loss_fn, self.cfg, self.impl
         shards = self._shards()
         if shards <= 1:
-            deltas, losses = fl_client.batched_local_sgd(
-                loss_fn, params, xs, ys, lr, rngs, cfg, steps,
-                num_steps=num_steps, num_examples=num_examples)
-            if cluster_sel is not None:
-                return fl_server.aggregate_hierarchical(
-                    params, deltas, coeffs, cluster_sel,
-                    num_clusters), losses
-            return fl_server.aggregate_fused(params, deltas, coeffs,
-                                             impl=impl), losses
+            with jax.named_scope(scopes.LOCAL_SGD):
+                deltas, losses = fl_client.batched_local_sgd(
+                    loss_fn, params, xs, ys, lr, rngs, cfg, steps,
+                    num_steps=num_steps, num_examples=num_examples)
+            with jax.named_scope(scopes.AGGREGATE):
+                if cluster_sel is not None:
+                    return fl_server.aggregate_hierarchical(
+                        params, deltas, coeffs, cluster_sel,
+                        num_clusters), losses
+                return fl_server.aggregate_fused(params, deltas, coeffs,
+                                                 impl=impl), losses
         k = xs.shape[0]
         if k % shards:
             raise ValueError(
@@ -257,11 +260,13 @@ class RoundEngine:
 
         if cluster_sel is not None:
             def body_h(params, lr, xs, ys, coeffs, rngs, ns, ne, csel):
-                deltas, losses = fl_client.batched_local_sgd(
-                    loss_fn, params, xs, ys, lr, rngs, cfg, steps,
-                    num_steps=ns, num_examples=ne)
-                new_params = fl_server.aggregate_hierarchical_psum(
-                    params, deltas, coeffs, csel, num_clusters, axis)
+                with jax.named_scope(scopes.LOCAL_SGD):
+                    deltas, losses = fl_client.batched_local_sgd(
+                        loss_fn, params, xs, ys, lr, rngs, cfg, steps,
+                        num_steps=ns, num_examples=ne)
+                with jax.named_scope(scopes.AGGREGATE):
+                    new_params = fl_server.aggregate_hierarchical_psum(
+                        params, deltas, coeffs, csel, num_clusters, axis)
                 return new_params, losses
 
             sharded = jax.shard_map(
@@ -273,11 +278,13 @@ class RoundEngine:
                            num_examples, cluster_sel)
 
         def body(params, lr, xs, ys, coeffs, rngs, ns, ne):
-            deltas, losses = fl_client.batched_local_sgd(
-                loss_fn, params, xs, ys, lr, rngs, cfg, steps,
-                num_steps=ns, num_examples=ne)
-            new_params = fl_server.aggregate_fused_psum(
-                params, deltas, coeffs, axis, impl=impl)
+            with jax.named_scope(scopes.LOCAL_SGD):
+                deltas, losses = fl_client.batched_local_sgd(
+                    loss_fn, params, xs, ys, lr, rngs, cfg, steps,
+                    num_steps=ns, num_examples=ne)
+            with jax.named_scope(scopes.AGGREGATE):
+                new_params = fl_server.aggregate_fused_psum(
+                    params, deltas, coeffs, axis, impl=impl)
             return new_params, losses
 
         # P(axis) specs on the None masks apply to zero leaves — one spec
@@ -307,17 +314,20 @@ class RoundEngine:
         (``[N]`` int32, optional) routes the round's aggregation through
         the hierarchical reduce (see :meth:`_round_core`).
         """
-        xs = jnp.take(all_x, selected, axis=0)
-        if all_scale is not None:
-            shape = selected.shape + (1,) * (xs.ndim - 1)
-            xs = (xs.astype(jnp.float32)
-                  * jnp.take(all_scale, selected).reshape(shape)
-                  + jnp.take(all_zero, selected).reshape(shape))
-        ys = jnp.take(all_y, selected, axis=0)
-        ns = None if all_steps is None else jnp.take(all_steps, selected)
-        ne = None if all_sizes is None else jnp.take(all_sizes, selected)
-        csel = (None if cluster_of is None
-                else jnp.take(cluster_of, selected))
+        with jax.named_scope(scopes.GATHER):
+            xs = jnp.take(all_x, selected, axis=0)
+            if all_scale is not None:
+                shape = selected.shape + (1,) * (xs.ndim - 1)
+                xs = (xs.astype(jnp.float32)
+                      * jnp.take(all_scale, selected).reshape(shape)
+                      + jnp.take(all_zero, selected).reshape(shape))
+            ys = jnp.take(all_y, selected, axis=0)
+            ns = None if all_steps is None else jnp.take(all_steps,
+                                                         selected)
+            ne = None if all_sizes is None else jnp.take(all_sizes,
+                                                         selected)
+            csel = (None if cluster_of is None
+                    else jnp.take(cluster_of, selected))
         return self._round_core(params, xs, ys, coeffs, lr, rngs, ns, ne,
                                 steps, cluster_sel=csel,
                                 num_clusters=num_clusters)
@@ -357,32 +367,37 @@ class RoundEngine:
         """
         upd, losses = None, jnp.zeros(pos_sel.shape, jnp.float32)
         for tid, xs, ys, ns, ne, sc, zp, steps in parts:
-            mask = tier_sel == tid
-            pos = jnp.where(mask, pos_sel, 0)
-            cf = coeffs * mask.astype(coeffs.dtype)
+            with jax.named_scope(scopes.tier(xs.shape[1])):
+                mask = tier_sel == tid
+                pos = jnp.where(mask, pos_sel, 0)
+                cf = coeffs * mask.astype(coeffs.dtype)
 
-            def run_tier(pos, cf, xs=xs, ys=ys, ns=ns, ne=ne, sc=sc,
-                         zp=zp, steps=steps, mask=mask):
-                p_t, l_t = self._gathered_round(params, xs, ys, ns, ne,
-                                                sc, zp, pos, cf, lr,
-                                                rngs, steps)
-                u_t = jax.tree_util.tree_map(lambda a, b: a - b, p_t,
-                                             params)
-                return u_t, l_t.astype(jnp.float32) * mask
+                def run_tier(pos, cf, xs=xs, ys=ys, ns=ns, ne=ne, sc=sc,
+                             zp=zp, steps=steps, mask=mask):
+                    p_t, l_t = self._gathered_round(params, xs, ys, ns, ne,
+                                                    sc, zp, pos, cf, lr,
+                                                    rngs, steps)
+                    with jax.named_scope(scopes.AGGREGATE):
+                        u_t = jax.tree_util.tree_map(lambda a, b: a - b,
+                                                     p_t, params)
+                    return u_t, l_t.astype(jnp.float32) * mask
 
-            if cond_skip:
-                def skip_tier(pos, cf):
-                    return (jax.tree_util.tree_map(jnp.zeros_like, params),
-                            jnp.zeros(pos_sel.shape, jnp.float32))
+                if cond_skip:
+                    def skip_tier(pos, cf):
+                        return (jax.tree_util.tree_map(jnp.zeros_like,
+                                                       params),
+                                jnp.zeros(pos_sel.shape, jnp.float32))
 
-                u_t, l_t = jax.lax.cond(jnp.any(mask), run_tier, skip_tier,
-                                        pos, cf)
-            else:
-                u_t, l_t = run_tier(pos, cf)
-            upd = (u_t if upd is None else
-                   jax.tree_util.tree_map(jnp.add, upd, u_t))
+                    u_t, l_t = jax.lax.cond(jnp.any(mask), run_tier,
+                                            skip_tier, pos, cf)
+                else:
+                    u_t, l_t = run_tier(pos, cf)
+            with jax.named_scope(scopes.AGGREGATE):
+                upd = (u_t if upd is None else
+                       jax.tree_util.tree_map(jnp.add, upd, u_t))
             losses = losses + l_t
-        new_params = jax.tree_util.tree_map(jnp.add, params, upd)
+        with jax.named_scope(scopes.AGGREGATE):
+            new_params = jax.tree_util.tree_map(jnp.add, params, upd)
         return new_params, losses
 
     # -- single fused round ------------------------------------------------
@@ -617,11 +632,12 @@ class RoundEngine:
 
             def round_fn(params, data, selected, coeffs, lr, rngs):
                 bufs, tier_of, pos = data
+                with jax.named_scope(scopes.GATHER):
+                    tier_sel = jnp.take(tier_of, selected)
+                    pos_sel = jnp.take(pos, selected)
                 return self._tier_loop_round(
-                    params, _tier_parts(parts_key, bufs),
-                    jnp.take(tier_of, selected),
-                    jnp.take(pos, selected), coeffs, lr, rngs,
-                    cond_skip=True)
+                    params, _tier_parts(parts_key, bufs), tier_sel,
+                    pos_sel, coeffs, lr, rngs, cond_skip=True)
 
             data = (tuple(buffers), bank.tier_of_device, bank.pos_device)
             return round_fn, data, parts_key
@@ -636,6 +652,18 @@ class RoundEngine:
         return (round_fn,
                 (all_x, all_y, all_steps, all_sizes, all_scale, all_zero),
                 (steps, masked, all_scale is not None))
+
+    def round_sgd_steps(self, bank_key: tuple) -> int:
+        """Sequential local-SGD steps of one scan round over the data
+        plane ``bank_key`` (from :meth:`_scan_plan`): local epochs x the
+        ``steps`` each tier passes to ``batched_local_sgd``, summed over
+        the key's tiers.  Under a lane ``vmap`` every tier runs, since
+        its ``lax.cond`` lowers to a select there."""
+        if isinstance(bank_key[0], tuple):      # a tier ladder
+            steps = sum(part[1] for part in bank_key)
+        else:
+            steps = bank_key[0]
+        return self.cfg.local_epochs * steps
 
     def _build_scan(self, k: int, decide_fn, round_fn, select_fn=None,
                     eval_fn=None, eval_every: int = 0,
@@ -749,65 +777,70 @@ class RoundEngine:
                     t_idx, h, alive, lr = inp
                 else:
                     t_idx, h, lr = inp
-                dec = decide_fn(sp_run, h, queues, V, lam, cid, kvec)
-                rng, k_sel, k_cli = jax.random.split(rng, 3)
-                # slot fill from the decision — every mode's slot i
-                # depends only on (round inputs, i), never on K_max:
-                # the padded-K invariant above
-                drawn = select_fn(sp_run, t_idx, h, queues, dec.q, k_sel,
-                                  slots, kvec, cid)
-                selected = jnp.where(active, drawn, 0)
-                rngs = jax.vmap(
-                    lambda i: jax.random.fold_in(k_cli, i))(slots)
-                if use_dropout:
-                    # realized dropouts zero the slot exactly like a
-                    # padded slot; `act` replaces `af` everywhere a
-                    # surviving upload is what counts
-                    act = af * jnp.take(alive, selected)
-                else:
-                    act = af
-                coeffs = (jnp.take(w, selected) /
-                          (jnp.take(kvec, selected) *
-                           jnp.take(dec.q, selected)) * act)
+                with jax.named_scope(scopes.DECIDE):
+                    dec = decide_fn(sp_run, h, queues, V, lam, cid, kvec)
+                with jax.named_scope(scopes.SELECT):
+                    rng, k_sel, k_cli = jax.random.split(rng, 3)
+                    # slot fill from the decision — every mode's slot i
+                    # depends only on (round inputs, i), never on K_max:
+                    # the padded-K invariant above
+                    drawn = select_fn(sp_run, t_idx, h, queues, dec.q,
+                                      k_sel, slots, kvec, cid)
+                    selected = jnp.where(active, drawn, 0)
+                    rngs = jax.vmap(
+                        lambda i: jax.random.fold_in(k_cli, i))(slots)
+                    if use_dropout:
+                        # realized dropouts zero the slot exactly like a
+                        # padded slot; `act` replaces `af` everywhere a
+                        # surviving upload is what counts
+                        act = af * jnp.take(alive, selected)
+                    else:
+                        act = af
+                    coeffs = (jnp.take(w, selected) /
+                              (jnp.take(kvec, selected) *
+                               jnp.take(dec.q, selected)) * act)
                 params, losses = round_fn(params, data, selected, coeffs,
                                           lr, rngs)
-                queues = vq.update_queues(
-                    queues,
-                    vq.energy_increment(sp_run, h, dec.p, dec.f, dec.q,
-                                        k=kvec))
-                t = sm.round_time(sp_run, h, dec.p, dec.f, k=kvec)
-                e = sm.round_energy(sp_run, h, dec.p, dec.f, k=kvec)
-                if use_dropout:
-                    loss = (jnp.sum(losses * act) /
-                            jnp.maximum(jnp.sum(act), 1.0))
-                    live = active & (act > 0.0)
-                    # all slots dropped: no upload finished this round
-                    wall = jnp.maximum(jnp.max(jnp.where(
-                        live, jnp.take(t, selected), -jnp.inf)), 0.0)
-                else:
-                    loss = jnp.sum(losses * af) / k_f
-                    live = active
-                    wall = jnp.max(jnp.where(
-                        live, jnp.take(t, selected), -jnp.inf))
-                # inactive slots scatter to the dropped out-of-range row n
-                mask = jnp.zeros((n,), jnp.float32).at[
-                    jnp.where(live, selected, n)].set(1.0, mode="drop")
-                out = dict(
-                    loss=loss,
-                    wall_time=wall,
-                    energy_mean=(jnp.sum(e * mask) /
-                                 jnp.maximum(jnp.sum(mask), 1.0)),
-                    queue_mean=jnp.mean(queues),
-                    queue_norm=jnp.linalg.norm(queues),
-                    q_min=jnp.min(dec.q), q_max=jnp.max(dec.q),
-                    selected=jnp.where(active, selected, -1),
-                )
+                with jax.named_scope(scopes.QUEUES):
+                    queues = vq.update_queues(
+                        queues,
+                        vq.energy_increment(sp_run, h, dec.p, dec.f, dec.q,
+                                            k=kvec))
+                    t = sm.round_time(sp_run, h, dec.p, dec.f, k=kvec)
+                    e = sm.round_energy(sp_run, h, dec.p, dec.f, k=kvec)
+                    if use_dropout:
+                        loss = (jnp.sum(losses * act) /
+                                jnp.maximum(jnp.sum(act), 1.0))
+                        live = active & (act > 0.0)
+                        # all slots dropped: no upload finished this round
+                        wall = jnp.maximum(jnp.max(jnp.where(
+                            live, jnp.take(t, selected), -jnp.inf)), 0.0)
+                    else:
+                        loss = jnp.sum(losses * af) / k_f
+                        live = active
+                        wall = jnp.max(jnp.where(
+                            live, jnp.take(t, selected), -jnp.inf))
+                    # inactive slots scatter to the dropped out-of-range
+                    # row n
+                    mask = jnp.zeros((n,), jnp.float32).at[
+                        jnp.where(live, selected, n)].set(1.0, mode="drop")
+                    out = dict(
+                        loss=loss,
+                        wall_time=wall,
+                        energy_mean=(jnp.sum(e * mask) /
+                                     jnp.maximum(jnp.sum(mask), 1.0)),
+                        queue_mean=jnp.mean(queues),
+                        queue_norm=jnp.linalg.norm(queues),
+                        q_min=jnp.min(dec.q), q_max=jnp.max(dec.q),
+                        selected=jnp.where(active, selected, -1),
+                    )
                 if eval_fn is not None:
-                    last_ev = jax.lax.cond(
-                        (t_idx + 1) % eval_every == 0,
-                        lambda op: eval_fn(op[0], eval_data),
-                        lambda op: op[1],
-                        (params, last_ev))
+                    with jax.named_scope(scopes.EVAL):
+                        last_ev = jax.lax.cond(
+                            (t_idx + 1) % eval_every == 0,
+                            lambda op: eval_fn(op[0], eval_data),
+                            lambda op: op[1],
+                            (params, last_ev))
                     out.update({"test_" + name: v
                                 for name, v in last_ev.items()})
                     return (params, queues, rng, last_ev), out

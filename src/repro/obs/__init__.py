@@ -2,7 +2,7 @@
 the engine/arena/service stack.
 
 Three instruments, one contract (no-ops unless enabled, never inside a
-jit):
+jit), and one list of names:
 
 * :mod:`repro.obs.trace` — nestable host-side spans (``arena.plan`` /
   ``arena.compile`` / ``arena.dispatch`` / ``arena.reduce`` /
@@ -17,21 +17,23 @@ jit):
 * :mod:`repro.obs.watchdog` — the retrace/compile sentinel: armed by
   ``Arena.warmup``, it turns any post-warmup scan-body retrace or cold
   compile into a structured event (or a raise, in strict mode) with the
-  offending executable-cache-key diff, and reduces the streaming path's
-  per-chunk dispatch/reduce latencies to stall percentiles.
+  offending executable-cache-key diff.
+* :mod:`repro.obs.scopes` — the ``jax.named_scope`` names of the device
+  program's layers (``fl.decide`` ... ``fl.eval``), which a device
+  profile shows on every compiled op.
 
 ``tools/obs_report.py`` renders a JSONL flight-recorder file into the
 per-phase time breakdown and health summary.
 """
 
-from repro.obs import trace
+from repro.obs import scopes, trace
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.trace import (JsonlSink, MemorySink, export_chrome_trace,
                              install_sink, installed, load_jsonl,
                              remove_sink, span, to_chrome_trace)
 from repro.obs.watchdog import RetraceError, Watchdog
 
-__all__ = ["trace", "span", "MemorySink", "JsonlSink", "installed",
-           "install_sink", "remove_sink", "load_jsonl", "to_chrome_trace",
-           "export_chrome_trace", "Counter", "Gauge", "Histogram",
-           "MetricsRegistry", "RetraceError", "Watchdog"]
+__all__ = ["scopes", "trace", "span", "MemorySink", "JsonlSink",
+           "installed", "install_sink", "remove_sink", "load_jsonl",
+           "to_chrome_trace", "export_chrome_trace", "Counter", "Gauge",
+           "Histogram", "MetricsRegistry", "RetraceError", "Watchdog"]

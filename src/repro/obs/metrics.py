@@ -22,9 +22,6 @@ docs/architecture.md):
   ``RolloutReport.dispatch_accounting``)
 * ``arena.input_cache.hits`` / ``arena.input_cache.misses`` —
   device-input caches (lane constants, channels, lr schedules)
-* ``arena.chunk.dispatch_s`` / ``arena.chunk.reduce_s`` — streaming
-  per-chunk dispatch-call and host-reduction latencies (histograms;
-  the watchdog's stall percentiles read these)
 * ``service.batches`` / ``service.scenarios`` / ``service.seconds`` /
   ``service.coalesced_lanes`` / ``service.queue_depth``
 * ``store.saves`` / ``store.loads``

@@ -35,9 +35,11 @@ Design constraints (the observability contract, pinned by
   or anything with an ``emit(record) -> None``.  ``installed()``
   context-manages a sink's lifetime for tests and benches.
 * **jax.profiler bridge.**  ``profiler_bridge(True)`` additionally
-  enters a ``jax.profiler.TraceAnnotation`` per span, so a captured
-  device profile (``jax.profiler.trace``) shows the same taxonomy; off
-  by default because annotations cost even when no profile is active.
+  enters a ``jax.profiler.TraceAnnotation`` per span, its scalar
+  attributes as the event's stats, so a captured device profile
+  (``jax.profiler.trace``) shows the same taxonomy and counts (the
+  ``arena.dispatch`` span's ``sgd_steps`` / ``rows_trained``); off by
+  default because annotations cost even when no profile is active.
 
 Span taxonomy (see docs/architecture.md "Observability"): dotted
 ``layer.phase`` names — ``arena.plan`` / ``arena.probe`` /
@@ -105,6 +107,9 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# attribute types the profiler bridge passes on as the annotation's stats
+_SCALARS = (bool, int, float, str)
+
 
 class _Span:
     __slots__ = ("name", "attrs", "id", "parent", "depth", "t0",
@@ -132,7 +137,9 @@ class _Span:
         if _PROFILER_BRIDGE:        # pragma: no cover - needs profiler
             try:
                 from jax.profiler import TraceAnnotation
-                self._annotation = TraceAnnotation(self.name)
+                self._annotation = TraceAnnotation(
+                    self.name, **{k: v for k, v in self.attrs.items()
+                                  if isinstance(v, _SCALARS)})
                 self._annotation.__enter__()
             except Exception:
                 self._annotation = None
@@ -277,10 +284,11 @@ def installed(sink: Any):
 def profiler_bridge(enabled: bool) -> None:
     """Mirror every live span as a ``jax.profiler.TraceAnnotation`` so a
     captured device profile (Perfetto / TensorBoard) carries the same
-    span taxonomy.  Off by default — annotations are not free even
-    without an active profile, and the no-sink fast path must stay
-    untouched (the bridge only fires on spans a sink already made
-    live)."""
+    span taxonomy, with the span's scalar attributes (the values
+    entered with it, not those ``set`` later) as the event's stats.  Off
+    by default — annotations are not free even without an active
+    profile, and the no-sink fast path must stay untouched (the bridge
+    only fires on spans a sink already made live)."""
     global _PROFILER_BRIDGE
     _PROFILER_BRIDGE = bool(enabled)
 

@@ -21,12 +21,9 @@ watchdog turns that diff into an automatic contract:
 * The baseline then advances, so one regression is reported once, not
   on every later run.
 
-The watchdog also owns the streaming-path stall view: the arena records
-each chunk's dispatch-call and host-reduce latency into the shared
-metrics registry (``arena.chunk.dispatch_s`` / ``arena.chunk.reduce_s``)
-— :meth:`stall_report` reduces them to percentiles, making an in-flight
-window stall (a dispatch call that blocks because the pipeline is
-``in_flight`` deep) visible as a fat p99 instead of a mystery.
+The streaming path's stall view is the ``arena.dispatch`` /
+``arena.reduce`` spans: ``tools/obs_report.py``'s health summary reduces
+them to p99 / p50 stall ratios over a flight log.
 """
 
 from __future__ import annotations
@@ -111,23 +108,3 @@ class Watchdog:
             f"trace(s), new cache keys {violation['new_executables']})",
             RuntimeWarning, stacklevel=2)
         return violation
-
-    # -- streaming stall view ------------------------------------------------
-
-    @staticmethod
-    def stall_report(metrics) -> Dict[str, Dict[str, float]]:
-        """Dispatch/reduce latency percentiles of the streaming path
-        from the shared registry — ``{phase: {p50, p90, p99, mean,
-        count}}``.  A dispatch p99 far above p50 means the in-flight
-        window blocked (device fell behind the host's dispatch rate)."""
-        out: Dict[str, Dict[str, float]] = {}
-        for phase, name in (("dispatch", "arena.chunk.dispatch_s"),
-                            ("reduce", "arena.chunk.reduce_s")):
-            h = metrics.get(name, default=None)
-            if h is None or not getattr(h, "count", 0):
-                continue
-            ps = h.percentiles()
-            out[phase] = {"p50": ps[50.0], "p90": ps[90.0],
-                          "p99": ps[99.0], "mean": h.mean,
-                          "count": h.count}
-        return out

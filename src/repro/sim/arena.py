@@ -85,7 +85,6 @@ import dataclasses
 import hashlib
 import itertools
 import math
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -552,6 +551,8 @@ class Arena:
         #: chunks ahead of the reduction
         self.in_flight = 2
         self._fns: Dict[tuple, Any] = {}
+        # (sgd steps, rows per lane) of one round, per executable key
+        self._work: Dict[tuple, Tuple[int, int]] = {}
         # control-plane probe executables / replayed footprints, kept
         # OUT of self._fns so executables_cached keeps counting rollout
         # programs only
@@ -842,6 +843,11 @@ class Arena:
             donate = (1,) if self.engine.donate else ()
         fn = jax.jit(batched, donate_argnums=donate)
         self._fns[key] = fn
+        # one round's local-SGD work, for the dispatch spans: sequential
+        # steps of one lane program and the rows one lane trains, padded
+        # slots and tiers included
+        steps = self.engine.round_sgd_steps(key[0])
+        self._work[key] = (steps, steps * self.engine.cfg.batch_size * k)
         return fn
 
     @staticmethod
@@ -937,6 +943,12 @@ class Arena:
                 f"sizes must split evenly across shards)")
         lane = self._lane_inputs(grid, sp)
         n = sp.num_devices
+        # one dispatched round's work; lanes run one after another under
+        # 'map'
+        round_steps, lane_rows = self._work[key]
+        if self.batch != "vmap":
+            round_steps *= s // self._shards()
+        round_rows = lane_rows * s
         eval_data = None if ek is None else eval_bank.device_args()
         h_all = jnp.asarray(h_all, jnp.float32)
         lr_dev = self._lr_device(lr_seq)
@@ -963,7 +975,9 @@ class Arena:
                     fn.lower(*args).compile()
                 return None, None, None, built, 0
             with obs.span("arena.dispatch", k_max=int(k_max), lanes=s,
-                          rounds=num_rounds, cold=bool(built)):
+                          rounds=num_rounds, cold=bool(built),
+                          sgd_steps=round_steps * num_rounds,
+                          rows_trained=round_rows * num_rounds):
                 params, queues, _, outs = fn(*args)
             with obs.span("arena.reduce", k_max=int(k_max), lanes=s,
                           rounds=num_rounds):
@@ -1057,25 +1071,23 @@ class Arena:
         def reduce_oldest():
             outs_d, ln_r, idx = pending.pop(0)
             # np.asarray blocks only on THIS chunk's output buffers —
-            # later chunks keep executing asynchronously (the span /
-            # latency histogram therefore measure the honest stall: how
-            # long the host waited for device work to catch up)
-            t_red = time.perf_counter()
+            # later chunks keep executing asynchronously (the span
+            # therefore measures the honest stall: how long the host
+            # waited for device work to catch up)
             with obs.span("arena.reduce", chunk=idx, rounds=int(ln_r),
                           k_max=int(k_max), lanes=s):
                 reduced.append({name: np.asarray(v)
                                 for name, v in outs_d.items()})
-            self.metrics.histogram("arena.chunk.reduce_s").observe(
-                time.perf_counter() - t_red)
 
         dispatches = 0
         for i, (t0, ln) in enumerate(segments):
             while len(pending) >= self.in_flight:
                 reduce_oldest()
             h_seg, lr_seg = h_all[:, t0:t0 + ln], lr_dev[t0:t0 + ln]
-            t_disp = time.perf_counter()
             with obs.span("arena.dispatch", chunk=i, t0=int(t0),
-                          rounds=int(ln), k_max=int(k_max), lanes=s):
+                          rounds=int(ln), k_max=int(k_max), lanes=s,
+                          sgd_steps=round_steps * ln,
+                          rows_trained=round_rows * ln):
                 if carry is None and i == 0 and t_start == 0:
                     q0 = jnp.zeros((s, n), jnp.float32)
                     params, queues, extras, outs = fn(
@@ -1085,8 +1097,6 @@ class Arena:
                     params, queues, extras, outs = rfn(
                         *resume_args(carry, h_seg, drop_seg(t0, ln),
                                      lr_seg, t0))
-            self.metrics.histogram("arena.chunk.dispatch_s").observe(
-                time.perf_counter() - t_disp)
             dispatches += 1
             carry = (params, queues, extras)
             pending.append((outs, ln, i))

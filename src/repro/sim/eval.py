@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import scopes
+
 PyTree = Any
 
 
@@ -65,7 +67,8 @@ class EvalBank:
         so the ``test_*`` columns and ``final_metrics`` cannot diverge."""
         def eval_fn(params: PyTree, data) -> Dict[str, jax.Array]:
             x, y = data
-            return task.metrics(params, {"x": x, "y": y})
+            with jax.named_scope(scopes.EVAL):
+                return task.metrics(params, {"x": x, "y": y})
         return eval_fn
 
     def device_args(self):

@@ -91,6 +91,11 @@ from repro.fl.client_bank import ClientBank, TieredClientBank
 PyTree = Any
 AnyBank = Any   # ClientBank | TieredClientBank
 
+#: the scan's per-round ``[T]`` bool column of the tiers that trained
+#: (tier ladders only); the arena counts it and neither it nor
+#: :meth:`RoundEngine.run_scan` reports it as a metric
+TIERS_RUN = "tiers_run"
+
 
 def _tier_parts(parts_key: tuple, buffers: tuple) -> list:
     """Zip the static per-tier key ``(tid, steps, masked[, quant])`` with
@@ -333,7 +338,8 @@ class RoundEngine:
                                 num_clusters=num_clusters)
 
     def _tier_loop_round(self, params, parts, tier_sel, pos_sel, coeffs,
-                         lr, rngs, cond_skip: bool = False):
+                         lr, rngs, cond_skip: bool = False,
+                         lane_axis: Optional[str] = None):
         """THE tier loop: one fused gathered round per tier, contributions
         summed across tiers.
 
@@ -361,11 +367,24 @@ class RoundEngine:
         loop, and a missed tier's contribution was exactly zero anyway
         (zeroed coefficients), so the two modes agree.  Off by default:
         ``round_step`` routes hit tiers on the host, every part it
-        passes is non-empty, and a cond would only add overhead (under
-        ``vmap`` — the ScenarioArena — the cond degenerates to running
-        both branches and selecting, which is still correct).
+        passes is non-empty, and a cond would only add overhead.
+
+        ``lane_axis``: the name of an enclosing lane ``vmap`` (the
+        ScenarioArena's).  A per-lane predicate is batched there, and a
+        cond on a batched predicate lowers to running both branches and
+        selecting, so every lane would train every tier.  With the axis
+        named, the predicate is decided across the lanes (``psum`` of the
+        per-lane hits, which comes back unbatched): the cond stays a real
+        branch, a tier that no lane drew is skipped for all of them, and
+        a tier that any lane drew runs for all of them, exactly as
+        without the skip.
+
+        Returns ``(new_params, losses, ran)``; with ``cond_skip``,
+        ``ran`` (``[len(parts)]`` bool) says which tiers trained this
+        round, and without it ``ran`` is None.
         """
         upd, losses = None, jnp.zeros(pos_sel.shape, jnp.float32)
+        ran = []
         for tid, xs, ys, ns, ne, sc, zp, steps in parts:
             with jax.named_scope(scopes.tier(xs.shape[1])):
                 mask = tier_sel == tid
@@ -388,8 +407,13 @@ class RoundEngine:
                                                        params),
                                 jnp.zeros(pos_sel.shape, jnp.float32))
 
-                    u_t, l_t = jax.lax.cond(jnp.any(mask), run_tier,
-                                            skip_tier, pos, cf)
+                    hit = jnp.any(mask)
+                    if lane_axis is not None:
+                        hit = jax.lax.psum(hit.astype(jnp.int32),
+                                           lane_axis) > 0
+                    u_t, l_t = jax.lax.cond(hit, run_tier, skip_tier, pos,
+                                            cf)
+                    ran.append(hit)
                 else:
                     u_t, l_t = run_tier(pos, cf)
             with jax.named_scope(scopes.AGGREGATE):
@@ -398,7 +422,7 @@ class RoundEngine:
             losses = losses + l_t
         with jax.named_scope(scopes.AGGREGATE):
             new_params = jax.tree_util.tree_map(jnp.add, params, upd)
-        return new_params, losses
+        return new_params, losses, jnp.stack(ran) if ran else None
 
     # -- single fused round ------------------------------------------------
 
@@ -492,10 +516,10 @@ class RoundEngine:
         quant)`` per hit tier — buffer pytrees arrive as a matching
         tuple."""
         def step(params, buffers, tier_sel, pos_sel, coeffs, rngs, lr):
-            return self._tier_loop_round(params,
-                                         _tier_parts(parts_key, buffers),
-                                         tier_sel, pos_sel, coeffs, lr,
-                                         rngs)
+            new_params, losses, _ = self._tier_loop_round(
+                params, _tier_parts(parts_key, buffers), tier_sel, pos_sel,
+                coeffs, lr, rngs)
+            return new_params, losses
 
         donate = (0,) if self.donate else ()
         return jax.jit(step, donate_argnums=donate)
@@ -574,31 +598,38 @@ class RoundEngine:
 
     # -- multi-round scan fast path ----------------------------------------
 
-    def _scan_plan(self, bank: AnyBank, tier_subset=None):
+    def _scan_plan(self, bank: AnyBank, tier_subset=None,
+                   lane_axis: Optional[str] = None):
         """(round_fn, data, bank_key) — the data-plane half of a rollout
         over ``bank``: ``round_fn(params, data, selected, coeffs, lr,
-        rngs)`` is the single-bucket gathered round or the tier loop, and
-        ``data`` the opaque device-buffer pytree it consumes.  Shared by
-        :meth:`run_scan` and the ScenarioArena (``repro.sim``), so the
-        host-looped and scenario-batched rollouts ride ONE data plane.
-        A one-tier ladder collapses to its single bucket here (bitwise
-        the :class:`ClientBank` plan); a multi-tier ladder's round runs
-        every tier under a selection-conditioned ``lax.cond``
-        (``cond_skip`` — rounds whose draw lands in few tiers stop
-        paying ``K * sum_t B_t`` work).
+        rngs) -> (params, losses, tiers_run)`` is the single-bucket
+        gathered round (``tiers_run`` None) or the tier loop (``[T]``
+        bool, the tiers that trained), and ``data`` the opaque
+        device-buffer pytree it consumes.  Shared by :meth:`run_scan` and
+        the ScenarioArena (``repro.sim``), so the host-looped and
+        scenario-batched rollouts ride ONE data plane.  A one-tier ladder
+        collapses to its single bucket here (bitwise the
+        :class:`ClientBank` plan); a multi-tier ladder's round runs each
+        tier under a selection-conditioned ``lax.cond`` (``cond_skip`` —
+        rounds whose draw lands in few tiers stop paying
+        ``K * sum_t B_t`` work).  ``lane_axis`` names the caller's lane
+        ``vmap`` (the arena's; None elsewhere): the skip is then decided
+        across the lanes, so the cond stays a real branch and a tier is
+        skipped in a round where no lane drew it (see
+        :meth:`_tier_loop_round`).
 
         ``tier_subset`` (sorted tier-id tuple, tiered banks only) builds
         the round against a STATIC subset of the ladder: tiers outside
         the subset simply do not exist in the trace.  This is the arena
         dispatch planner's scan-skip lever — a bucket of lanes that can
-        never draw tier ``t`` compiles a body without it, recovering the
-        skewed-ladder win that ``cond_skip`` loses under ``vmap`` (cond
-        lowers to select there, so every tier body executes).  Callers
-        OWN the safety argument: selections that land outside the subset
-        would gather garbage positions; the planner only emits subsets
-        covering each lane's replayed footprint.  The returned
-        ``bank_key`` keeps the per-tier layout triples, so distinct
-        subsets cache distinct executables."""
+        never draw tier ``t`` over the whole rollout compiles a body
+        without it, where the runtime skip would still pay each round's
+        predicate and skips only rounds in which no lane of the bucket
+        drew the tier.  Callers OWN the safety argument: selections that
+        land outside the subset would gather garbage positions; the
+        planner only emits subsets covering each lane's replayed
+        footprint.  The returned ``bank_key`` keeps the per-tier layout
+        triples, so distinct subsets cache distinct executables."""
         if isinstance(bank, TieredClientBank) and bank.num_tiers == 1:
             bank = bank.tiers[0]            # the ladder IS one bucket
         if not isinstance(bank, TieredClientBank):
@@ -637,7 +668,8 @@ class RoundEngine:
                     pos_sel = jnp.take(pos, selected)
                 return self._tier_loop_round(
                     params, _tier_parts(parts_key, bufs), tier_sel,
-                    pos_sel, coeffs, lr, rngs, cond_skip=True)
+                    pos_sel, coeffs, lr, rngs, cond_skip=True,
+                    lane_axis=lane_axis)
 
             data = (tuple(buffers), bank.tier_of_device, bank.pos_device)
             return round_fn, data, parts_key
@@ -646,8 +678,8 @@ class RoundEngine:
         steps, masked = bank.steps_per_epoch, all_steps is not None
 
         def round_fn(params, data, selected, coeffs, lr, rngs):
-            return self._gathered_round(params, *data, selected, coeffs,
-                                        lr, rngs, steps)
+            return (*self._gathered_round(params, *data, selected, coeffs,
+                                          lr, rngs, steps), None)
 
         return (round_fn,
                 (all_x, all_y, all_steps, all_sizes, all_scale, all_zero),
@@ -657,8 +689,9 @@ class RoundEngine:
         """Sequential local-SGD steps of one scan round over the data
         plane ``bank_key`` (from :meth:`_scan_plan`): local epochs x the
         ``steps`` each tier passes to ``batched_local_sgd``, summed over
-        the key's tiers.  Under a lane ``vmap`` every tier runs, since
-        its ``lax.cond`` lowers to a select there."""
+        the key's tiers.  That is the work dispatched, an upper bound on
+        the work done: a tier that no lane drew in a round is skipped
+        (the ``tiers_run`` scan column says which ran)."""
         if isinstance(bank_key[0], tuple):      # a tier ladder
             steps = sum(part[1] for part in bank_key)
         else:
@@ -799,8 +832,8 @@ class RoundEngine:
                     coeffs = (jnp.take(w, selected) /
                               (jnp.take(kvec, selected) *
                                jnp.take(dec.q, selected)) * act)
-                params, losses = round_fn(params, data, selected, coeffs,
-                                          lr, rngs)
+                params, losses, tiers_run = round_fn(
+                    params, data, selected, coeffs, lr, rngs)
                 with jax.named_scope(scopes.QUEUES):
                     queues = vq.update_queues(
                         queues,
@@ -834,6 +867,8 @@ class RoundEngine:
                         q_min=jnp.min(dec.q), q_max=jnp.max(dec.q),
                         selected=jnp.where(active, selected, -1),
                     )
+                    if tiers_run is not None:
+                        out[TIERS_RUN] = tiers_run
                 if eval_fn is not None:
                     with jax.named_scope(scopes.EVAL):
                         last_ev = jax.lax.cond(
@@ -962,5 +997,6 @@ class RoundEngine:
                 jnp.int32(pol.POLICY_IDS[policy]),
                 jnp.full((n,), sp.sample_count, jnp.float32),
                 jnp.int32(sp.sample_count), None, jnp.int32(0), None)
-            metrics = {name: np.asarray(v) for name, v in outs.items()}
+            metrics = {name: np.asarray(v) for name, v in outs.items()
+                       if name != TIERS_RUN}
         return params, queues, metrics

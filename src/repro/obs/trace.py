@@ -38,8 +38,10 @@ Design constraints (the observability contract, pinned by
   enters a ``jax.profiler.TraceAnnotation`` per span, its scalar
   attributes as the event's stats, so a captured device profile
   (``jax.profiler.trace``) shows the same taxonomy and counts (the
-  ``arena.dispatch`` span's ``sgd_steps`` / ``rows_trained``); off by
-  default because annotations cost even when no profile is active.
+  ``arena.dispatch`` span's ``sgd_steps`` / ``rows_trained``, the
+  ``arena.reduce`` span's ``tier_rounds_run`` / ``tier_rounds_skipped``);
+  off by default because annotations cost even when no profile is
+  active.
 
 Span taxonomy (see docs/architecture.md "Observability"): dotted
 ``layer.phase`` names — ``arena.plan`` / ``arena.probe`` /
@@ -122,8 +124,14 @@ class _Span:
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered mid-span (e.g. how many
-        executables a plan produced)."""
+        executables a plan produced); the scalar ones also go on the
+        live profiler annotation."""
         self.attrs.update(attrs)
+        if self._annotation is not None:  # pragma: no cover
+            scalars = {k: v for k, v in attrs.items()
+                       if isinstance(v, _SCALARS)}
+            if scalars:
+                self._annotation.set_metadata(**scalars)
         return self
 
     def __enter__(self) -> "_Span":
@@ -150,6 +158,7 @@ class _Span:
         t1 = time.perf_counter()
         if self._annotation is not None:  # pragma: no cover
             self._annotation.__exit__(*exc)
+            self._annotation = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -284,8 +293,8 @@ def installed(sink: Any):
 def profiler_bridge(enabled: bool) -> None:
     """Mirror every live span as a ``jax.profiler.TraceAnnotation`` so a
     captured device profile (Perfetto / TensorBoard) carries the same
-    span taxonomy, with the span's scalar attributes (the values
-    entered with it, not those ``set`` later) as the event's stats.  Off
+    span taxonomy, with the span's scalar attributes (those entered
+    with it and those ``set`` while it is open) as the event's stats.  Off
     by default — annotations are not free even without an active
     profile, and the no-sink fast path must stay untouched (the bridge
     only fires on spans a sink already made live)."""

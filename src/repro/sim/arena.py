@@ -25,10 +25,17 @@ the read-only (never-donated) ClientBank across all lanes:
   bisection solver) agree to float32 resolution (~1e-6 relative) rather
   than bitwise: XLA fuses those elementwise chains shape-dependently,
   so the batched and unbatched programs may round a final ulp apart.
-  Tiered banks relax the model half to f32 resolution too — the tier
-  loop's per-tier ``lax.cond`` lowers as a real branch unbatched but as
-  a both-branches select under vmap.  The contract is regression-tested
-  in ``tests/test_arena.py``.
+  Tiered banks relax the model half to f32 resolution too — each
+  tier's body runs batched over the lanes here and unbatched in
+  ``run_scan``, and XLA may round the two a final ulp apart.  The
+  contract is regression-tested in ``tests/test_arena.py``.
+* **Tier skip across lanes.**  The lane ``vmap`` carries the axis name
+  :data:`LANE_AXIS`, and the tier loop decides each tier's ``lax.cond``
+  across it (see ``RoundEngine._tier_loop_round``): a tier that no lane
+  drew in a round is skipped for all lanes, a tier that any lane drew
+  runs for all of them.  The arena counts the tier-rounds run and
+  skipped (``arena.tier_rounds_run`` / ``_skipped``, and on the
+  ``arena.reduce`` span).
 * **Channels pregenerated on device.**  Per-scenario (mean, clip) channel
   statistics feed a vmapped ``environment.sample_gains`` — the whole
   ``[S, T, N]`` gain tensor is drawn in one jit from the scenario seeds.
@@ -98,7 +105,7 @@ from repro.core.controller import estimate_hyperparams_arrays
 from repro.fl.environment import (CHANNEL_MODE_IDS, CHANNEL_MODES,
                                   sample_channel_sequence,
                                   sample_dropout_mask)
-from repro.fl.round_engine import bank_layout_key
+from repro.fl.round_engine import TIERS_RUN, bank_layout_key
 from repro.obs import trace as obs
 from repro.obs.metrics import MetricsRegistry
 from repro.sim.cost_model import CostModel
@@ -106,6 +113,9 @@ from repro.sim.dispatch import DispatchPlan, lane_footprints, plan_dispatch
 from repro.sim.report import RolloutReport, concat_chunk_metrics
 
 PyTree = Any
+
+#: the name of the lane ``vmap``: the tier loop decides its skip across it
+LANE_AXIS = "lanes"
 
 
 def _as_f32(value, s: int) -> np.ndarray:
@@ -482,8 +492,10 @@ class Arena:
       are replayed by a control-plane probe (selections depend only on
       the control plane, never on training — the same determinism the
       lane-equivalence tests pin down), so a bucket whose lanes never
-      draw tier ``t`` compiles a scan body without it, recovering the
-      skewed-ladder scan-skip that ``vmap`` otherwise erases.  Results
+      draw tier ``t`` compiles a scan body without it — where the
+      padded program's per-round skip (a tier drawn by no lane of the
+      program) pays each round's predicate, and runs the tier for every
+      lane in a round where any lane drew it.  Results
       are stitched back to grid order (device-side ``concatenate`` +
       ``take`` per params leaf); per-bucket lanes stay bitwise-equal on
       the model trajectory to their pad/group counterparts.
@@ -794,7 +806,8 @@ class Arena:
             batched = jax.vmap(scan_fn,
                                in_axes=(p_ax, 0, None, 0, None, 0, d_ax,
                                         None, 0, 0, 0, 0, 0, 0, None,
-                                        None, ev_ax))
+                                        None, ev_ax),
+                               axis_name=LANE_AXIS)
         else:
             def batched(params, queues, sp, eb, data, h_seq, drop_seq,
                         lr_seq, rng, V, lam, cid, kvec, k_act, eval_data,
@@ -922,8 +935,9 @@ class Arena:
         use_dropout = drop_all is not None
         if use_dropout:
             drop_all = jnp.asarray(drop_all, jnp.float32)
-        round_fn, data, bank_key = self.engine._scan_plan(bank,
-                                                          tier_subset)
+        round_fn, data, bank_key = self.engine._scan_plan(
+            bank, tier_subset,
+            lane_axis=LANE_AXIS if self.batch == "vmap" else None)
         ek = self._eval_key(eval_bank, eval_every)
         key = (bank_key, k_max, self._shards(), ek, use_dropout)
         built = 0
@@ -980,9 +994,8 @@ class Arena:
                           rows_trained=round_rows * num_rounds):
                 params, queues, _, outs = fn(*args)
             with obs.span("arena.reduce", k_max=int(k_max), lanes=s,
-                          rounds=num_rounds):
-                metrics = {name: np.asarray(v)
-                           for name, v in outs.items()}
+                          rounds=num_rounds) as red:
+                metrics = self._reduce_outs(outs, red)
             return params, queues, metrics, built, 1
 
         chunk = (num_rounds if chunk_size is None
@@ -1075,9 +1088,8 @@ class Arena:
             # therefore measures the honest stall: how long the host
             # waited for device work to catch up)
             with obs.span("arena.reduce", chunk=idx, rounds=int(ln_r),
-                          k_max=int(k_max), lanes=s):
-                reduced.append({name: np.asarray(v)
-                                for name, v in outs_d.items()})
+                          k_max=int(k_max), lanes=s) as red:
+                reduced.append(self._reduce_outs(outs_d, red))
 
         dispatches = 0
         for i, (t0, ln) in enumerate(segments):
@@ -1121,6 +1133,27 @@ class Arena:
             chunk_store.finish(tag)
         params, queues, _ = carry
         return params, queues, metrics, built, dispatches
+
+    def _reduce_outs(self, outs: dict, red) -> dict:
+        """One dispatch's metric columns as host arrays, less the tier
+        loop's ``tiers_run`` flags, which are counted instead: tier-rounds
+        run and skipped, one per tier and round of each program that
+        decides its skip on its own (a shard's lane ``vmap``, or one lane
+        under ``'map'``).  The counts go on the ``arena.reduce`` span
+        ``red`` and the ``arena.tier_rounds_run`` / ``_skipped``
+        counters."""
+        host = {name: np.asarray(v) for name, v in outs.items()}
+        ran = host.pop(TIERS_RUN, None)
+        if ran is not None:
+            if self.batch == "vmap":
+                # every lane of a shard's vmap holds its shard's flags
+                ran = ran[::ran.shape[0] // self._shards()]
+            run = int(np.count_nonzero(ran))
+            skipped = int(ran.size) - run
+            red.set(tier_rounds_run=run, tier_rounds_skipped=skipped)
+            self.metrics.counter("arena.tier_rounds_run").inc(run)
+            self.metrics.counter("arena.tier_rounds_skipped").inc(skipped)
+        return host
 
     # -- shape-adaptive dispatch planning -----------------------------------
 
@@ -1172,7 +1205,7 @@ class Arena:
                                         skey, slots, kvec)
 
             def noop_round(params, data, selected, coeffs, lr, rngs):
-                return params, jnp.zeros(selected.shape, jnp.float32)
+                return params, jnp.zeros(selected.shape, jnp.float32), None
 
             inner = self.engine._build_scan(k_max, decide, noop_round,
                                             select_fn=select)

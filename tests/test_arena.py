@@ -23,6 +23,7 @@ from repro.core import policy as pol
 from repro.data import synthetic_image_classification
 from repro.fl import ClientConfig, RoundEngine
 from repro.models import MLPTask
+from repro.obs import scopes
 from repro.sim import (Arena, CostModel, EvalBank, RolloutReport,
                        ScenarioGrid, derive_hyperparams, scenario_keys)
 
@@ -72,10 +73,9 @@ def _assert_lane_matches(rep, eng, bank, sp, params0, grid, h_all, lr, s,
     """Arena lane ``s`` == the individual run_scan reproduction of it.
 
     ``model_bitwise=False`` relaxes the model trajectory to tight
-    allclose — the tiered scan's per-tier ``lax.cond`` lowers as a real
-    branch in the unbatched program but as a both-branches select under
-    the arena's vmap, so tiered lanes agree to f32 resolution instead of
-    bitwise."""
+    allclose — each tier's body runs batched over the arena's lanes and
+    unbatched in run_scan, which XLA may round a final ulp apart, so
+    tiered lanes agree to f32 resolution instead of bitwise."""
     _, roll_keys = scenario_keys(grid)
     sp_s = grid.scenario_system_params(sp, s)
     p, q, m = eng.run_scan(params0, sp_s, bank, np.asarray(h_all[s]), lr,
@@ -564,14 +564,174 @@ def test_tier_loop_cond_skip_matches_unconditional():
             jnp.float32(0.1), rngs, cond_skip=cond_skip))
         return fn(params0)
 
-    p_cond, l_cond = run(True)
-    p_ref, l_ref = run(False)
+    p_cond, l_cond, ran_cond = run(True)
+    p_ref, l_ref, ran_ref = run(False)
+    # the flags name the tiers the selection drew
+    np.testing.assert_array_equal(
+        np.asarray(ran_cond),
+        np.isin(np.arange(len(bank.tiers)), bank.tier_of[sel]))
+    assert ran_ref is None
     for a, b in zip(jax.tree_util.tree_leaves(p_cond),
                     jax.tree_util.tree_leaves(p_ref)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-7)
     np.testing.assert_allclose(np.asarray(l_cond), np.asarray(l_ref),
                                atol=1e-7)
+
+
+def _skip_grid():
+    """Two lanes at K=2 on the tiered bank of ``_skip_setup``: round 1
+    of the rollout draws one tier in both lanes, so the other three
+    tiers are drawn by no lane, while round 2 draws three tiers."""
+    return ScenarioGrid.create(controllers=["round_robin", "uni_d"],
+                               seeds=[0, 1], V=10.0, lam=0.5,
+                               sample_count=2)
+
+
+def _skip_setup():
+    return _setup([64, 10, 33, 64, 100, 17], bank_mode="tiered")
+
+
+def _tier_rounds(arena):
+    return (arena.metrics.counter("arena.tier_rounds_run").value,
+            arena.metrics.counter("arena.tier_rounds_skipped").value)
+
+
+def _drawn_tiers(bank, selected):
+    """``[lanes, T, tiers]`` bool: the tiers each lane drew each round."""
+    tiers = bank.tier_of[np.asarray(selected)]
+    return np.stack([(tiers == t).any(axis=-1)
+                     for t in range(bank.num_tiers)], axis=-1)
+
+
+def _run_skip_grid(batch="vmap", lane_axis=None, monkeypatch=None):
+    import repro.sim.arena as arena_mod
+    if monkeypatch is not None:
+        monkeypatch.setattr(arena_mod, "LANE_AXIS", lane_axis)
+    task, eng, bank, sp, params0 = _skip_setup()
+    grid = _skip_grid()
+    arena = Arena(eng, batch=batch)
+    T = 4
+    lr = np.full(T, 0.1, np.float32)
+    h_all = arena.sample_channels(grid, T, len(bank.tier_of))
+    rep = arena.run(params0, sp, bank, grid, T, lr, h_all=h_all)
+    return arena, rep, (eng, bank, sp, params0, grid, h_all, lr)
+
+
+def _assert_lanes_close(rep, ref):
+    for a, b in zip(jax.tree_util.tree_leaves(rep.params),
+                    jax.tree_util.tree_leaves(ref.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-7)
+    np.testing.assert_allclose(rep.metrics["loss"], ref.metrics["loss"],
+                               atol=1e-7)
+    np.testing.assert_array_equal(rep.metrics["selected"],
+                                  ref.metrics["selected"])
+
+
+def test_arena_tier_skip_matches_every_tier_path(monkeypatch):
+    """A tier that no lane drew in a round is skipped for every lane; the
+    lanes match the every-tier path (the vmap without a lane axis, where
+    each tier's cond lowers to a select) and the selections are equal."""
+    arena, rep, (_, bank, *_rest) = _run_skip_grid()
+    assert bank.num_tiers > 1
+    # the grid does exercise the skip: some round draws a tier in no lane
+    drawn = _drawn_tiers(bank, rep.metrics["selected"])
+    assert not drawn.any(axis=0).all()
+    assert _tier_rounds(arena)[1] > 0
+    _, every_tier, _ = _run_skip_grid(lane_axis=None,
+                                      monkeypatch=monkeypatch)
+    _assert_lanes_close(rep, every_tier)
+
+
+def _tier_scope_prims(closed):
+    """``{fl.tier.<rows>: {primitive names}}`` of the equations whose
+    innermost scope is a tier scope, anywhere in ``closed``."""
+    import re
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            scope = str(eqn.source_info.name_stack).split("/")[-1]
+            if re.fullmatch(r"fl\.tier\.\d+", scope):
+                found.setdefault(scope, set()).add(eqn.primitive.name)
+            for v in eqn.params.values():
+                for x in (v if isinstance(v, (list, tuple)) else [v]):
+                    if isinstance(x, ClosedJaxpr):
+                        walk(x.jaxpr)
+                    elif isinstance(x, Jaxpr):
+                        walk(x)
+
+    walk(closed.jaxpr)
+    return found
+
+
+def _arena_jaxpr(arena, rerun):
+    """The jaxpr of the arena's one rollout executable, traced with the
+    arguments a second ``rerun()`` passes it."""
+    (key, fn), = arena._fns.items()
+    seen = []
+
+    def record(*args):
+        seen.append(args)
+        return fn(*args)
+
+    arena._fns[key] = record
+    rerun()
+    arena._fns[key] = fn
+    return jax.make_jaxpr(fn)(*seen[0])
+
+
+def test_arena_tier_skip_keeps_a_real_cond_under_vmap(monkeypatch):
+    """The vmapped arena program holds a ``cond`` around each tier body,
+    not the ``select_n`` a per-lane predicate lowers to."""
+    for axis in ("lanes", None):
+        arena, rep, (eng, bank, sp, params0, grid, h_all, lr) = \
+            _run_skip_grid(lane_axis=axis, monkeypatch=monkeypatch)
+        prims = _tier_scope_prims(_arena_jaxpr(
+            arena, lambda: arena.run(params0, sp, bank, grid, 4, lr,
+                                     h_all=h_all)))
+        want = {scopes.tier(t.bucket_examples) for t in bank.tiers}
+        assert set(prims) == want
+        for scope, names in prims.items():
+            if axis is None:        # the every-tier path: both branches
+                assert "cond" not in names and "select_n" in names, scope
+            else:
+                assert "cond" in names and "select_n" not in names, scope
+
+
+def test_arena_tier_skip_counters_match_host_count():
+    """``arena.tier_rounds_run`` / ``_skipped`` count, per round and tier,
+    whether any lane drew the tier, as the host computes it from the
+    selections; the ``arena.reduce`` span carries the same counts."""
+    from repro.obs import trace as obs_trace
+    with obs_trace.installed(obs_trace.MemorySink()) as sink:
+        arena, rep, (_, bank, *_rest) = _run_skip_grid()
+    any_lane = _drawn_tiers(bank, rep.metrics["selected"]).any(axis=0)
+    run, skipped = _tier_rounds(arena)
+    assert (run, skipped) == (int(any_lane.sum()),
+                              int((~any_lane).sum()))
+    assert skipped > 0
+    (red,) = sink.by_name("arena.reduce")
+    assert red["attrs"]["tier_rounds_run"] == run
+    assert red["attrs"]["tier_rounds_skipped"] == skipped
+    assert "tiers_run" not in rep.metrics
+
+
+def test_arena_tier_skip_map_mode_lanes_unchanged():
+    """``batch='map'`` runs each lane's unbatched scan: its tiers skip per
+    lane, its lanes reproduce the tiered run_scan bitwise, and they agree
+    with the vmapped arena's lanes."""
+    arena, rep, (eng, bank, sp, params0, grid, h_all, lr) = \
+        _run_skip_grid(batch="map")
+    per_lane = _drawn_tiers(bank, rep.metrics["selected"])
+    assert _tier_rounds(arena) == (int(per_lane.sum()),
+                                   int((~per_lane).sum()))
+    for s in range(len(grid)):
+        _assert_lane_matches(rep, eng, bank, sp, params0, grid, h_all, lr,
+                             s)
+    _, rep_vmap, _ = _run_skip_grid()
+    _assert_lanes_close(rep, rep_vmap)
 
 
 # -- on-device batched evaluation ------------------------------------------
